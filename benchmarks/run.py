@@ -278,6 +278,8 @@ def main(argv=None) -> None:
         records = payload.get("benchmarks", [])
         failed = payload.get("failed", [])
     else:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         names = args.names or list(ALL)
         records = []
         for name in names:

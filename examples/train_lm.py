@@ -48,9 +48,9 @@ def main():
     cfg = full_lm() if args.full else small_lm()
     print(f"{cfg.name}: {param_count(cfg)/1e6:.1f}M params")
     gc = GradCompConfig(bits=args.bits, strategy="allgather_packed")
-    _, losses = train(cfg, steps=args.steps, batch_size=args.batch,
-                      seq_len=args.seq, gc=gc, lr=3e-3, log_every=10,
-                      ckpt_dir=args.ckpt_dir)
+    _, losses, _ = train(cfg, steps=args.steps, batch_size=args.batch,
+                         seq_len=args.seq, gc=gc, lr=3e-3, log_every=10,
+                         ckpt_dir=args.ckpt_dir)
     print(f"\nloss: {losses[0]:.3f} → {losses[-1]:.3f} "
           f"over {len(losses)} steps (R={args.bits} bits/dim on the wire)")
 

@@ -49,23 +49,26 @@ class TokenStream:
 
 
 @partial(jax.jit, static_argnames=("vocab",))
-def _markov_logits(table_key: jax.Array, vocab: int) -> jax.Array:
-    # low-rank logits table: (V, r) @ (r, V) so big vocabs stay cheap
+def _markov_factors(table_key: jax.Array, vocab: int) -> tuple:
+    # low-rank logits table (V, r) @ (r, V), kept as its factors: a row is
+    # formed only when a token needs it, so a 50k vocab never holds V² floats
     r = 32
     ka, kb = jax.random.split(table_key)
     a = jax.random.normal(ka, (vocab, r))
     b = jax.random.normal(kb, (r, vocab))
-    return a @ b / jnp.sqrt(r)
+    return a, b
 
 
 def _markov_tokens(key, table_key, batch, length, vocab, temperature):
-    logits = _markov_logits(table_key, vocab) / temperature
+    a, b = _markov_factors(table_key, vocab)
+    r = a.shape[1]
 
     k0, kscan = jax.random.split(key)
     first = jax.random.randint(k0, (batch,), 0, vocab, jnp.int32)
 
     def step(tok, k):
-        nxt = jax.random.categorical(k, logits[tok])
+        logits = a[tok] @ b / jnp.sqrt(r) / temperature
+        nxt = jax.random.categorical(k, logits)
         return nxt.astype(jnp.int32), nxt.astype(jnp.int32)
 
     keys = jax.random.split(kscan, length - 1)

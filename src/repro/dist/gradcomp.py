@@ -279,6 +279,17 @@ def decode_leaf(payload: dict, leaf_idx: int, size: int, shape, dtype,
     return flat.reshape(lead + tuple(shape)).astype(dtype)
 
 
+def worker_mean(stacked: jax.Array) -> jax.Array:
+    """Mean over the leading worker axis of decoded payloads, summed left
+    to right. A reduce would pick its summation order from the array's
+    layout; the all-gather path decodes whole leaves and ZeRO-1 only its
+    owned rows, and both must round alike."""
+    total = stacked[0]
+    for w in range(1, stacked.shape[0]):
+        total = total + stacked[w]
+    return total / stacked.shape[0]
+
+
 # ---------------------------------------------------------------------------
 # Tree codec (what the consensus strategies move around)
 # ---------------------------------------------------------------------------

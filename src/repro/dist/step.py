@@ -3,9 +3,9 @@
 Training runs as a fully-manual shard_map: gradients cross the data axes
 ("pod","data") only through the chosen consensus strategy, and params are
 replicated over "model" inside the step (partial-auto — manual data axes
-over a GSPMD-sharded model axis — crashes the pinned jax 0.4.x partitioner;
-see the NOTE in make_train_step). The tensor-parallel sharding from
-repro.dist.sharding drives the pure-jit serve / prefill paths.
+over a GSPMD-sharded model axis — is ROADMAP Reach 2). The tensor-parallel
+sharding from repro.dist.sharding drives the pure-jit serve / prefill
+paths.
 
 Consensus strategies (GradCompConfig.strategy):
 
@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.codecs import stages as codec_stages
 from repro.dist import gradcomp as G
 from repro.dist import zero as zero_lib
@@ -128,6 +127,16 @@ def _with_obs(fn, name: str, gc: G.GradCompConfig, payload_bytes):
 # ---------------------------------------------------------------------------
 # Consensus
 # ---------------------------------------------------------------------------
+def _pin(g):
+    """Materialize a gradient leaf, in its own shape, before the codec
+    touches it. Without the barrier XLA may fuse g's producer (a reduction,
+    a scatter-add) with the reshape, pad or EF add that follows — e.g. seed
+    the embedding's scatter-add with e instead of zeros — and sum in an
+    order that depends on that consumer, which differs between the
+    replicated and the ZeRO-1 layouts. Those must stay bit-identical."""
+    return jax.lax.optimization_barrier(g)
+
+
 def _consensus(grads, ef, gc: G.GradCompConfig, axes, round_idx):
     """Returns (consensus grads, new EF state).
 
@@ -143,7 +152,9 @@ def _consensus(grads, ef, gc: G.GradCompConfig, axes, round_idx):
     e_leaves = treedef.flatten_up_to(ef) if gc.uses_ef else [None] * len(leaves)
     outs, new_e = [], []
     for i, (g, e) in enumerate(zip(leaves, e_leaves)):
-        u = g.astype(jnp.float32) + (e if e is not None else 0.0)
+        u = _pin(g).astype(jnp.float32)
+        if e is not None:
+            u = u + e
         resid = None
         if gc.strategy == "allgather_packed" and gc.uses_ef:
             # fused encode + EF: the kernel decodes its own payload in-tile
@@ -165,7 +176,7 @@ def _consensus(grads, ef, gc: G.GradCompConfig, axes, round_idx):
                 lambda t: jax.lax.all_gather(t, axes, axis=0), payload)
             stacked = leaf_codec.decode(gathered, i, u.size, u.shape,
                                         jnp.float32, extra_lead=1)
-            cons = jnp.mean(stacked, axis=0)
+            cons = G.worker_mean(stacked)
         outs.append(cons.astype(g.dtype))
         if gc.uses_ef:
             new_e.append(resid)
@@ -208,17 +219,13 @@ def make_train_step(cfg, opt, gc: G.GradCompConfig, mesh, clip_norm=None,
 
     batch_spec = P(first)
     ef_spec = P(first) if gc.uses_ef else P()
-    # NOTE: ALL mesh axes are manual here — params enter with in_specs=P()
-    # and are therefore fully replicated (incl. over "model") inside the
-    # train step, on every jax version. Partial-auto shard_map (manual data
-    # axes over a GSPMD-sharded model axis) hard-crashes the 0.4.x SPMD
-    # partitioner; tensor-parallel param sharding still drives the pure-jit
-    # serve/prefill paths. Re-enabling partial-auto (axis_names=set(axes))
-    # once the toolchain moves off 0.4.x is tracked in ROADMAP.md.
-    fn = shard_map(local_step, mesh=mesh,
-                   in_specs=(P(), P(), ef_spec, batch_spec),
-                   out_specs=(P(), P(), ef_spec, P()),
-                   axis_names=set(mesh.axis_names))
+    # ALL mesh axes are manual here — params enter with in_specs=P() and
+    # are therefore fully replicated (incl. over "model") inside the train
+    # step; tensor-parallel param sharding drives only the pure-jit
+    # serve/prefill paths.
+    fn = jax.shard_map(local_step, mesh=mesh,
+                       in_specs=(P(), P(), ef_spec, batch_spec),
+                       out_specs=(P(), P(), ef_spec, P()), check_vma=False)
     return _with_obs(jax.jit(fn), "dist.step", gc,
                      _analytic_payload_bytes(cfg, gc, mesh))
 
@@ -323,10 +330,10 @@ def make_zero_train_step(cfg, opt, gc: G.GradCompConfig, mesh,
         sq_sum = jnp.zeros((), jnp.float32)
         for i, (g, e, (size, shape, dtype, (padded, rows))) in enumerate(
                 zip(g_leaves, e_leaves, infos)):
-            u = zero_lib.to_owned(g, gc.chunk, m)
+            u = zero_lib.to_owned(_pin(g), gc.chunk, m)
             if e is not None:
                 u = u + e[0]
-            mean_own, d_own = zero_lib.compressed_reduce_scatter(
+            mean_own, resid = zero_lib.compressed_reduce_scatter(
                 u, i, gc, axes, m, round_idx,
                 logical_chunks=-(-size // gc.chunk))
             # zero the padding coords so optimizer state / EF stay clean and
@@ -339,7 +346,7 @@ def make_zero_train_step(cfg, opt, gc: G.GradCompConfig, mesh,
             owned_grads.append(mean_own)
             sq_sum = sq_sum + jnp.sum(jnp.square(mean_own))
             if e is not None:
-                new_e.append(((u - d_own)
+                new_e.append((resid
                               * zero_lib.valid_mask(size, padded, gc.chunk)
                               )[None])
         grad_norm = jnp.sqrt(jax.lax.psum(sq_sum, axes))
@@ -365,10 +372,10 @@ def make_zero_train_step(cfg, opt, gc: G.GradCompConfig, mesh,
         lambda _: P(_lead_axes(axes)),
         p_shapes) if gc.uses_ef else {}
     batch_spec = P(_lead_axes(axes))
-    fn = shard_map(local_step, mesh=mesh,
-                   in_specs=(owned_spec, opt_spec, ef_spec, batch_spec),
-                   out_specs=(owned_spec, opt_spec, ef_spec, P()),
-                   axis_names=set(mesh.axis_names))
+    fn = jax.shard_map(local_step, mesh=mesh,
+                       in_specs=(owned_spec, opt_spec, ef_spec, batch_spec),
+                       out_specs=(owned_spec, opt_spec, ef_spec, P()),
+                       check_vma=False)
     return _with_obs(jax.jit(fn), "dist.step.zero1", gc,
                      _analytic_payload_bytes(cfg, gc, mesh))
 

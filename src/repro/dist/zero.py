@@ -76,13 +76,21 @@ def compressed_reduce_scatter(u: jax.Array, leaf_idx: int,
     codec draws its stochastic parts (keep-mask, dither) at that count so the
     payload stays bit-exact with the un-padded all-gather encode even at
     keep_fraction < 1 (the padded chunks are always dropped).
-    Returns (owned_mean (rows, chunk), decoded_own (padded_chunks, chunk)) —
-    the owner-side consensus mean for this worker's rows, and the local
-    decode of the worker's OWN payload (for its error-feedback update).
+    Returns (owned_mean (rows, chunk), residual) — the owner-side consensus
+    mean for this worker's rows, and, when `gc` uses error feedback, the
+    fused encoder's residual u − D(E(u)) of the worker's OWN payload
+    (padded_chunks, chunk), else None. The residual comes from the same
+    fused `encode_ef` the all-gather path uses, row for row, so the EF state
+    of the two paths stays bit-identical.
     """
     rows = u.shape[0] // num_workers
-    payload = G.encode_leaf(u, leaf_idx, gc, round_idx,
-                            logical_chunks=logical_chunks)
+    residual = None
+    if gc.uses_ef:
+        payload, residual = G.encode_leaf_ef(u, leaf_idx, gc, round_idx,
+                                             logical_chunks=logical_chunks)
+    else:
+        payload = G.encode_leaf(u, leaf_idx, gc, round_idx,
+                                logical_chunks=logical_chunks)
 
     def route(t):
         tm = t.reshape((num_workers, rows) + t.shape[1:])
@@ -94,7 +102,4 @@ def compressed_reduce_scatter(u: jax.Array, leaf_idx: int,
     gathered = jax.tree.map(route, payload)      # (m, rows, …) per wire leaf
     stacked = G.decode_leaf(gathered, leaf_idx, rows * gc.chunk,
                             (rows, gc.chunk), jnp.float32, gc, extra_lead=1)
-    owned_mean = jnp.mean(stacked, axis=0)
-    decoded_own = G.decode_leaf(payload, leaf_idx, u.size, u.shape,
-                                jnp.float32, gc)
-    return owned_mean, decoded_own
+    return G.worker_mean(stacked), residual

@@ -7,8 +7,7 @@ the same stacked trees are sharded over the mesh data axes — the axes
 `repro.dist.step` runs its consensus workers on — so each device runs its
 slice of client lanes (local SGD → encode → decode → per-lane norms) fully
 manually inside one `shard_map` program, consistent with the all-manual
-pattern proven in `repro.dist.step` (partial-auto shard_map crashes the
-pinned 0.4.x partitioner; see the NOTE there).
+pattern of `repro.dist.step`.
 
 Lane placement contract:
 
@@ -56,7 +55,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.obs import recompile as recompile_lib
 from repro.dist.sharding import (data_axis_names, lane_pspec, num_workers,
                                  padded_lanes)
@@ -102,10 +100,9 @@ def make_mesh_cohort_round(loss_fn, codec, client_cfg, params_template,
         decoded = jax.vmap(lambda w: codec.decode(w, meta))(wires)
         return wires, new_state, decoded, server_lib.stacked_norms(decoded)
 
-    fn = shard_map(local_lanes, mesh=mesh,
-                   in_specs=(P(), lane, lane, P()),
-                   out_specs=(lane, lane, lane, lane),
-                   axis_names=set(mesh.axis_names))
+    fn = jax.shard_map(local_lanes, mesh=mesh,
+                       in_specs=(P(), lane, lane, P()),
+                       out_specs=(lane, lane, lane, lane), check_vma=False)
     return jax.jit(fn)
 
 
@@ -151,9 +148,8 @@ def _mesh_mean_fn(mesh, sum_mode: str, lanes: int):
 
     return recompile_lib.register(
         "fed.aggregate.mesh",
-        jax.jit(shard_map(fold, mesh=mesh, in_specs=in_specs,
-                          out_specs=P(),
-                          axis_names=set(mesh.axis_names))))
+        jax.jit(jax.shard_map(fold, mesh=mesh, in_specs=in_specs,
+                              out_specs=P(), check_vma=False)))
 
 
 def _place_lanes(tree, mesh):

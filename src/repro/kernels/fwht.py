@@ -6,10 +6,14 @@ VMEM-resident (block_rows, N) tiles and run the radix-2 butterfly in-register:
 log₂N add/sub sweeps — the paper's "O(n log n) additions, no multiplies",
 mapped onto the VPU. N ≤ 8192 keeps a (8, 8192) f32 tile at 256 KiB << VMEM.
 
-The lane (last) dimension stays N throughout; butterflies reshape only the
-sublane structure, which lowers to cheap VPU shuffles for h ≥ 128 and to
-in-lane permutes below. (Validated in interpret mode on CPU; TPU is the
-deployment target.)
+Every value keeps the full (rows, N) shape: butterfly h pairs lane j with
+lane j ^ h through two lane rolls and a select on (j & h) == 0 —
+`x + x[j+h]` on the low half of each 2h block, `x[j-h] − x` on the high
+half. A reshape to (…, 2, h) would split the 128-lane dimension for
+h < 128, which the TPU compiler refuses; a roll by a multiple of 128 is a
+plain vreg move. `fwht_tile` is shared with the fused encoder
+(`quantencode.py`), and `ref.fwht` runs the same adds and subtracts in the
+same order, so all three agree bitwise.
 """
 from __future__ import annotations
 
@@ -19,24 +23,27 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BLOCK_ROWS = 8
 MAX_VMEM_N = 8192
 
 
-def _fwht_kernel(x_ref, o_ref, *, n: int):
-    x = x_ref[...]  # (block_rows, n)
-    rows = x.shape[0]
+def fwht_tile(x: jax.Array, n: int) -> jax.Array:
+    """Normalized FWHT of a resident (rows, n) tile along its lanes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     h = 1
     while h < n:
-        x = x.reshape(rows, n // (2 * h), 2, h)
-        a = x[:, :, 0, :]
-        b = x[:, :, 1, :]
-        x = jnp.stack([a + b, a - b], axis=2)
-        x = x.reshape(rows, n)
+        up = pltpu.roll(x, n - h, 1)        # up[j] = x[j + h]
+        down = pltpu.roll(x, h, 1)          # down[j] = x[j − h]
+        x = jnp.where((lane & h) == 0, x + up, down - x)
         h *= 2
-    o_ref[...] = x * (1.0 / math.sqrt(n))
+    return x * (1.0 / math.sqrt(n))
+
+
+def _fwht_kernel(x_ref, o_ref, *, n: int):
+    o_ref[...] = fwht_tile(x_ref[...], n)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

@@ -27,37 +27,23 @@ O(G·dh·log dh) instead of O(C·dh·log dh).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fwht import fwht_tile
+from repro.kernels.quantpack import dequantize_codes, unpack_tile
+
 
 DEFAULT_BLOCK_C = 512
 
 
-def _unpack_block(words: jax.Array, bits: int, dh: int) -> jax.Array:
-    """(bc, dh·bits/32) i32 → (bc, dh) f32 in [-1, 1) mid-rise levels."""
-    k = 32 // bits
-    m = 2 ** bits
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[None, None, :]
-    idx = (words.astype(jnp.uint32)[:, :, None] >> shifts) & jnp.uint32(m - 1)
-    idx = idx.reshape(words.shape[0], dh)
-    return -1.0 + (2.0 * idx.astype(jnp.float32) + 1.0) / m
-
-
-def _fwht_rows(x: jax.Array) -> jax.Array:
-    """Normalized FWHT along the last axis (rows in VMEM)."""
-    rows, n = x.shape
-    h = 1
-    while h < n:
-        x = x.reshape(rows, n // (2 * h), 2, h)
-        a, b = x[:, :, 0, :], x[:, :, 1, :]
-        x = jnp.stack([a + b, a - b], axis=2).reshape(rows, n)
-        h *= 2
-    return x * (1.0 / math.sqrt(n))
+def _unpack_block(words: jax.Array, bits: int) -> jax.Array:
+    """(bc, dh·bits/32) i32 planar words (see quantpack.py) → (bc, dh) f32
+    in [-1, 1) mid-rise levels."""
+    return dequantize_codes(unpack_tile(words, bits), bits)
 
 
 def _qdecode_kernel(q_ref, kw_ref, ks_ref, vw_ref, vs_ref, len_ref, o_ref,
@@ -72,7 +58,7 @@ def _qdecode_kernel(q_ref, kw_ref, ks_ref, vw_ref, vs_ref, len_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0]                                   # (G, dh) — pre-scaled
-    kd = _unpack_block(kw_ref[0], bits, dh) * ks_ref[0][:, None]  # (bc, dh)
+    kd = _unpack_block(kw_ref[0], bits) * ks_ref[0][:, None]  # (bc, dh)
     s = q @ kd.T                                      # (G, bc)
     pos = ic * block_c + jnp.arange(block_c, dtype=jnp.int32)
     valid = pos < len_ref[0]
@@ -82,7 +68,7 @@ def _qdecode_kernel(q_ref, kw_ref, ks_ref, vw_ref, vs_ref, len_ref, o_ref,
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
     p = jnp.exp(s - m_new[:, None])                   # (G, bc)
     corr = jnp.exp(m_prev - m_new)
-    vd = _unpack_block(vw_ref[0], bits, dh) * vs_ref[0][:, None]
+    vd = _unpack_block(vw_ref[0], bits) * vs_ref[0][:, None]
     acc_ref[...] = acc_ref[...] * corr[:, None] + p @ vd
     m_ref[...] = m_new
     l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1)
@@ -91,7 +77,7 @@ def _qdecode_kernel(q_ref, kw_ref, ks_ref, vw_ref, vs_ref, len_ref, o_ref,
     def _finish():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
         if inv_rotate_v:
-            out = _fwht_rows(out)                     # H is its own inverse
+            out = fwht_tile(out, dh)                  # H is its own inverse
         o_ref[0, 0] = out
 
 

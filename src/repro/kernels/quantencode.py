@@ -12,7 +12,7 @@ N·bits/32 words + one f32 scale per row", the codec's information-theoretic
 minimum (gated in `benchmarks/codec_roofline.py`).
 
 A fused error-feedback variant (`encode_ef_pallas`) additionally
-unpacks/dequantizes its own words in-tile, inverse-rotates, and emits the
+dequantizes its own codes in-tile, inverse-rotates, and emits the
 EF residual u − D(E(u)) alongside — the DGD-DEF update without a second
 pass over the leaf.
 
@@ -31,55 +31,16 @@ a tight tolerance rather than bitwise equality.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fwht import MAX_VMEM_N
+from repro.kernels.fwht import MAX_VMEM_N, fwht_tile
+from repro.kernels.quantpack import (dequantize_codes, pack_tile,
+                                     quantize_tile)
 
 DEFAULT_BLOCK_ROWS = 8
-
-
-def _fwht_tile(x: jax.Array, n: int) -> jax.Array:
-    """Radix-2 butterfly sweeps on a resident (rows, n) tile — the same op
-    sequence as ref.fwht, so compiled/interpret results match it bitwise."""
-    rows = x.shape[0]
-    h = 1
-    while h < n:
-        x = x.reshape(rows, n // (2 * h), 2, h)
-        a = x[:, :, 0, :]
-        b = x[:, :, 1, :]
-        x = jnp.stack([a + b, a - b], axis=2)
-        x = x.reshape(rows, n)
-        h *= 2
-    return x * (1.0 / math.sqrt(n))
-
-
-def _quantize_tile(x: jax.Array, scale: jax.Array, bits: int, n: int):
-    """(rows, n) f32 → (rows, n·bits/32) uint32 — same ops as ref.quantize_pack."""
-    k = 32 // bits
-    m = 2 ** bits
-    delta = 2.0 / m
-    normalized = x / jnp.maximum(scale, jnp.finfo(x.dtype).tiny)
-    idx = jnp.floor((jnp.clip(normalized, -1.0, 1.0) + 1.0) / delta)
-    idx = jnp.clip(idx, 0, m - 1).astype(jnp.uint32)
-    grouped = idx.reshape(idx.shape[0], n // k, k)
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[None, None, :]
-    return jnp.sum(grouped << shifts, axis=-1, dtype=jnp.uint32)
-
-
-def _dequantize_tile(words: jax.Array, scale: jax.Array, bits: int, n: int):
-    """Inverse of _quantize_tile — same ops as ref.unpack_dequant."""
-    k = 32 // bits
-    m = 2 ** bits
-    mask = jnp.uint32(m - 1)
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[None, None, :]
-    idx = (words.astype(jnp.uint32)[:, :, None] >> shifts) & mask
-    idx = idx.reshape(words.shape[0], n)
-    values = -1.0 + (2.0 * idx.astype(jnp.float32) + 1.0) / m
-    return values * scale
 
 
 def _encode_kernel(*refs, bits: int, n: int, dithered: bool, masked: bool,
@@ -99,32 +60,33 @@ def _encode_kernel(*refs, bits: int, n: int, dithered: bool, masked: bool,
 
     u = x_ref[...]                                    # (rows, n) f32 input
     signs = signs_ref[...]                            # (1, n) ±1 f32
-    embedded = _fwht_tile(u * signs, n)               # x = H·D·u
+    embedded = fwht_tile(u * signs, n)                # x = H·D·u
     scale = jnp.max(jnp.abs(embedded), axis=-1, keepdims=True)
     if dithered:
         embedded = embedded + dither_ref[...] * scale
-    words = _quantize_tile(embedded, scale, bits, n)
+    codes = quantize_tile(embedded, scale, bits)
     out_scale = scale
-    out_words = words.astype(jnp.int32)
     if masked:
         mask = mask_ref[...]                          # (rows, 1) 0/1 f32
-        out_words = out_words * mask.astype(jnp.int32)
+        codes = codes * mask.astype(jnp.int32)
         out_scale = scale * mask
-    words_ref[...] = out_words
+    words_ref[...] = pack_tile(codes, bits)
     scale_ref[...] = out_scale
 
     if ef:
         # decode the tile's OWN (masked) payload in-tile, replaying
         # decode_leaf's op order exactly: dequant → mask → (1/keep rescale)
-        # → FWHT → sign-flip → leaf-dtype rounding → subtract. The residual
-        # never leaves VMEM un-reduced: u is already resident, so the EF
-        # state costs no second pass over the leaf.
-        x_hat = _dequantize_tile(out_words, out_scale, bits, n)
+        # → FWHT → sign-flip → leaf-dtype rounding → subtract. The codes
+        # are what unpacking the words would give back (a dropped row's
+        # zero words unpack to zero codes), so no unpack is needed. The
+        # residual never leaves VMEM un-reduced: u is already resident, so
+        # the EF state costs no second pass over the leaf.
+        x_hat = dequantize_codes(codes, bits) * out_scale
         if masked:
             x_hat = x_hat * mask_ref[...]
             if rescale is not None:
                 x_hat = x_hat / rescale
-        y_hat = _fwht_tile(x_hat, n) * signs
+        y_hat = fwht_tile(x_hat, n) * signs
         y_hat = y_hat.astype(residual_dtype).astype(jnp.float32)
         residual_ref[...] = u - y_hat
 

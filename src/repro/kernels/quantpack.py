@@ -5,6 +5,15 @@ embedding, each chunk is scaled by 1/‖x‖∞, uniformly quantized to R bits a
 bit-packed into int32 words — all inside one VMEM tile, so the intermediate
 per-element integer codes never round-trip through HBM. The decoder fuses the
 inverse. bits ∈ {1, 2, 4, 8} (packing factor k = 32/bits).
+
+Word layout (planar): a row of N codes packs into W = N/k words, and slot i
+(bits [i·bits, (i+1)·bits)) of word w holds the code of element i·W + w.
+Packing is then lane-local: shift each code by its slot, OR-fold the row
+onto its first W lanes with log₂k lane rolls, keep those lanes. Unpacking
+tiles the W words k times along the lanes and shifts each slot back down.
+No value ever splits the 128-lane dimension, and all integer work is int32
+with logical right shifts (bits=1 uses bit 31). `ref.quantize_pack` /
+`ref.unpack_dequant` define the same layout with the same float ops.
 """
 from __future__ import annotations
 
@@ -13,40 +22,59 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BLOCK_ROWS = 8
 
 
-def _quantpack_kernel(x_ref, scale_ref, o_ref, *, bits: int, n: int):
-    x = x_ref[...]                       # (rows, n) float
-    scale = scale_ref[...]               # (rows, 1) float
-    k = 32 // bits
+def quantize_tile(x: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
+    """(rows, n) float, (rows, 1) scale → (rows, n) int32 codes in [0, 2^bits)."""
     m = 2 ** bits
-    delta = 2.0 / m
     normalized = x / jnp.maximum(scale, jnp.finfo(x.dtype).tiny)
-    idx = jnp.floor((jnp.clip(normalized, -1.0, 1.0) + 1.0) / delta)
-    idx = jnp.clip(idx, 0, m - 1).astype(jnp.uint32)
-    grouped = idx.reshape(idx.shape[0], n // k, k)
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[None, None, :]
-    words = jnp.sum(grouped << shifts, axis=-1, dtype=jnp.uint32)
-    o_ref[...] = words.astype(jnp.int32)
+    idx = jnp.floor((jnp.clip(normalized, -1.0, 1.0) + 1.0) * (m / 2))
+    return jnp.clip(idx, 0, m - 1).astype(jnp.int32)
 
 
-def _unpackdequant_kernel(w_ref, scale_ref, o_ref, *, bits: int, n: int):
-    words = w_ref[...].astype(jnp.uint32)   # (rows, n//k)
-    scale = scale_ref[...]                   # (rows, 1)
-    k = 32 // bits
+def pack_tile(idx: jax.Array, bits: int) -> jax.Array:
+    """(rows, n) int32 codes → (rows, n·bits/32) int32 planar words."""
+    n = idx.shape[1]
+    w = n * bits // 32
+    lane = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1)
+    t = idx << ((lane // w) * bits)
+    s = n // 2
+    while s >= w:
+        t = t | pltpu.roll(t, n - s, 1)     # t[j] |= t[j + s]
+        s //= 2
+    return t[:, :w]
+
+
+def dequantize_codes(idx: jax.Array, bits: int) -> jax.Array:
+    """int32 codes → f32 mid-rise levels v_i = -1 + (2i+1)/M (unscaled)."""
     m = 2 ** bits
-    mask = jnp.uint32(m - 1)
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[None, None, :]
-    idx = (words[:, :, None] >> shifts) & mask
-    idx = idx.reshape(words.shape[0], n)
-    values = -1.0 + (2.0 * idx.astype(o_ref.dtype) + 1.0) / m
-    o_ref[...] = values * scale
+    return -1.0 + (2.0 * idx.astype(jnp.float32) + 1.0) * (1.0 / m)
 
 
-def _tile(call, flat_inputs, out_shape, block_rows):
+def unpack_tile(words: jax.Array, bits: int) -> jax.Array:
+    """(rows, W) int32 planar words → (rows, W·32/bits) int32 codes."""
+    k = 32 // bits
+    w = words.shape[1]
+    tiled = pltpu.repeat(words, k, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, tiled.shape, 1)
+    return jax.lax.shift_right_logical(tiled, (lane // w) * bits) & (2 ** bits - 1)
+
+
+def _quantpack_kernel(x_ref, scale_ref, o_ref, *, bits: int):
+    o_ref[...] = pack_tile(quantize_tile(x_ref[...], scale_ref[...], bits),
+                           bits)
+
+
+def _unpackdequant_kernel(w_ref, scale_ref, o_ref, *, bits: int):
+    values = dequantize_codes(unpack_tile(w_ref[...], bits), bits)
+    o_ref[...] = values * scale_ref[...]
+
+
+def _tile(call, flat_inputs, block_rows):
     rows = flat_inputs[0].shape[0]
     padded = -(-rows // block_rows) * block_rows
     if padded != rows:
@@ -77,7 +105,7 @@ def quantize_pack_pallas(x: jax.Array, scale: jax.Array, bits: int,
     def call(padded_rows, inputs):
         grid = (padded_rows // block_rows,)
         return pl.pallas_call(
-            functools.partial(_quantpack_kernel, bits=bits, n=n),
+            functools.partial(_quantpack_kernel, bits=bits),
             grid=grid,
             in_specs=[pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
                       pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
@@ -86,7 +114,7 @@ def quantize_pack_pallas(x: jax.Array, scale: jax.Array, bits: int,
             interpret=interpret,
         )(*inputs)
 
-    out = _tile(call, [flat_x, flat_s], None, block_rows)
+    out = _tile(call, [flat_x, flat_s], block_rows)
     return out.reshape(lead + (n // k,))
 
 
@@ -111,7 +139,7 @@ def unpack_dequant_pallas(words: jax.Array, scale: jax.Array, bits: int, n: int,
     def call(padded_rows, inputs):
         grid = (padded_rows // block_rows,)
         return pl.pallas_call(
-            functools.partial(_unpackdequant_kernel, bits=bits, n=n),
+            functools.partial(_unpackdequant_kernel, bits=bits),
             grid=grid,
             in_specs=[pl.BlockSpec((block_rows, n // k), lambda i: (i, 0)),
                       pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
@@ -120,5 +148,5 @@ def unpack_dequant_pallas(words: jax.Array, scale: jax.Array, bits: int, n: int,
             interpret=interpret,
         )(*inputs)
 
-    out = _tile(call, [flat_w, flat_s], None, block_rows)
+    out = _tile(call, [flat_w, flat_s], block_rows)
     return out.reshape(lead + (n,))

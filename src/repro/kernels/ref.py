@@ -21,18 +21,19 @@ def fwht(x: jax.Array) -> jax.Array:
     n = x.shape[-1]
     if n & (n - 1):
         raise ValueError(f"FWHT length {n} is not a power of 2")
-    orig_shape = x.shape
-    y = x.reshape((-1, n))
+    # butterfly h pairs lane j with j ^ h via two rolls and a select — the
+    # op sequence of kernels/fwht.fwht_tile, with no (…, 2, h) layout that
+    # a TPU would pad out to 128 lanes
+    lane = jnp.arange(n, dtype=jnp.int32)
+    y = x
     h = 1
     while h < n:
-        y = y.reshape((-1, n // (2 * h), 2, h))
-        a = y[:, :, 0, :]
-        b = y[:, :, 1, :]
-        y = jnp.stack([a + b, a - b], axis=2)
-        y = y.reshape((-1, n))
+        up = jnp.roll(y, -h, axis=-1)        # up[j] = y[j + h]
+        down = jnp.roll(y, h, axis=-1)       # down[j] = y[j − h]
+        y = jnp.where((lane & h) == 0, y + up, down - y)
         h *= 2
     scale = jnp.asarray(1.0 / math.sqrt(n), x.dtype)
-    return (y * scale).reshape(orig_shape)
+    return y * scale
 
 
 def quantize_pack(x: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
@@ -42,8 +43,9 @@ def quantize_pack(x: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
     scale: broadcastable to x[..., :1] — the per-row dynamic range (‖x‖∞).
     bits:  ∈ {1, 2, 4, 8} — levels M = 2^bits on [-1, 1], v_i = -1 + (2i+1)/M.
 
-    Returns int32 words of shape (..., N * bits / 32); N must be divisible
-    by the packing factor k = 32 // bits.
+    Returns int32 words of shape (..., W) with W = N * bits / 32; N must be
+    divisible by the packing factor k = 32 // bits. Planar layout: slot i
+    (bits [i·bits, (i+1)·bits)) of word w holds the code of element i·W + w.
     """
     if bits not in (1, 2, 4, 8):
         raise ValueError(f"bits must be in {{1,2,4,8}}, got {bits}")
@@ -52,15 +54,16 @@ def quantize_pack(x: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
     if n % k:
         raise ValueError(f"N={n} not divisible by packing factor {k}")
     m = 2 ** bits
-    delta = 2.0 / m
+    w = n // k
     normalized = x / jnp.maximum(scale, jnp.finfo(x.dtype).tiny)
-    # nearest-neighbour index of v_i = -1 + (2i+1)/M
-    idx = jnp.floor((jnp.clip(normalized, -1.0, 1.0) + 1.0) / delta)
-    idx = jnp.clip(idx, 0, m - 1).astype(jnp.uint32)
-    grouped = idx.reshape(x.shape[:-1] + (n // k, k))
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[(None,) * (grouped.ndim - 1)]
-    words = jnp.sum(grouped << shifts, axis=-1, dtype=jnp.uint32)
-    return words.astype(jnp.int32)
+    # nearest-neighbour index of v_i = -1 + (2i+1)/M; ·M/2 is the exact
+    # power-of-two form of ÷(2/M)
+    idx = jnp.floor((jnp.clip(normalized, -1.0, 1.0) + 1.0) * (m / 2))
+    idx = jnp.clip(idx, 0, m - 1).astype(jnp.int32)
+    words = idx[..., :w]
+    for i in range(1, k):
+        words = words | (idx[..., i * w:(i + 1) * w] << (i * bits))
+    return words
 
 
 def encode(chunks: jax.Array, signs: jax.Array, bits: int, *,
@@ -123,8 +126,6 @@ def encode_ef(chunks: jax.Array, signs: jax.Array, bits: int, *,
     # decode's multiply→add chains into the subtract (exactly as it could
     # in the pre-fused decode-then-subtract composition), so the residual
     # is bit-stable only eagerly — the EF contract is tolerance-based.
-    # (jax.lax.optimization_barrier would pin it, but 0.4.x has no vmap
-    # batching rule for it and the fed cohort engine vmaps this path.)
     return words, scale, chunks.astype(jnp.float32) - y_hat
 
 
@@ -159,10 +160,8 @@ def unpack_dequant(words: jax.Array, scale: jax.Array, bits: int, n: int,
         raise ValueError(f"bits must be in {{1,2,4,8}}, got {bits}")
     k = 32 // bits
     m = 2 ** bits
-    mask = jnp.uint32(m - 1)
-    w = words.astype(jnp.uint32)[..., None]
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * bits)[(None,) * (words.ndim)]
-    idx = (w >> shifts) & mask
-    idx = idx.reshape(words.shape[:-1] + (words.shape[-1] * k,))[..., :n]
-    values = -1.0 + (2.0 * idx.astype(dtype) + 1.0) / m
+    idx = jnp.concatenate(
+        [jax.lax.shift_right_logical(words, i * bits) & (m - 1)
+         for i in range(k)], axis=-1)[..., :n]
+    values = -1.0 + (2.0 * idx.astype(dtype) + 1.0) * (1.0 / m)
     return values * scale

@@ -1,12 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: prove every (arch × shape × mesh) lowers AND compiles.
 
-The two lines above MUST stay first: jax locks the device count at first
-initialization, and the production meshes (16×16 and 2×16×16) need 512
-placeholder host devices. Do not set this flag anywhere global — tests and
-benches must see 1 device.
+The three lines above MUST stay first: jax locks the platform and the
+device count at first initialization, and the production meshes (16×16
+and 2×16×16) need 512 placeholder host devices. Pinning the CPU platform
+keeps this process off any attached TPU. Do not set these flags anywhere
+global — tests and benches must see their own devices.
 
 For each combination this entrypoint:
   1. builds the production mesh (single- or multi-pod),
@@ -123,18 +125,13 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool,
         # publishes the abstract mesh so in-model sharding hints
         # (with_sharding_constraint on raw PartitionSpecs, e.g. the MoE
         # expert-parallel dispatch buffer) resolve during tracing.
-        # (compat: no-op on jax 0.4.x, where the `with mesh:` context below
-        # is what repro.compat.get_mesh falls back to.)
-        from repro.compat import set_mesh
-        set_mesh(mesh)
+        jax.set_mesh(mesh)
         with mesh:
             lowered, model_flops = build_lowered(cfg, shape, mesh, gc,
                                                  opt_name)
             compiled = lowered.compile()
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict/device
-                cost = cost[0]
             text = compiled.as_text()
         n_dev = mesh.size
         roof = hlo_analysis.roofline_terms(cost, text, model_flops, n_dev)

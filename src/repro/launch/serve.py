@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.dist import step as step_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import decode as decode_lib
 from repro.models import model as model_lib
@@ -60,6 +61,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get(args.arch))
     serve(cfg, batch=args.batch, prompt_len=args.prompt_len, gen=args.gen)

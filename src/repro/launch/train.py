@@ -1,8 +1,9 @@
 """Training driver: end-to-end LM training with compressed gradient consensus.
 
-Runs for real on whatever devices exist (the CPU container: a 1×1 host mesh,
-where the shard_map collectives degenerate but the full codec path — FWHT
-embedding, R-bit pack, decode, error feedback, optimizer — executes exactly).
+Runs on whatever devices exist, on a 1×1 mesh unless given one: on a TPU
+the codec runs as compiled Pallas kernels; on a CPU the collectives
+degenerate but the full codec path — FWHT embedding, R-bit pack, decode,
+error feedback, optimizer — executes exactly through the jnp reference.
 
   PYTHONPATH=src python -m repro.launch.train --arch yi-6b --reduced \
       --steps 50 --batch 8 --seq 128 --bits 4
@@ -22,6 +23,7 @@ from repro.checkpoint import save_checkpoint
 from repro.data import batch_for_shape
 from repro.dist import step as step_lib
 from repro.dist.gradcomp import GradCompConfig, wire_bytes_tree
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optimizer import adamw, warmup_cosine
 
@@ -29,6 +31,11 @@ from repro.optimizer import adamw, warmup_cosine
 def train(cfg, *, steps: int, batch_size: int, seq_len: int,
           gc: GradCompConfig, lr: float = 3e-4, log_every: int = 10,
           ckpt_dir: str | None = None, mesh=None, seed: int = 0):
+    """Train for `steps` steps on `mesh` (default: one device).
+
+    Returns (params, per-step losses, per-step wall seconds); each step's
+    time ends when its outputs are ready, and the first one includes the
+    trace and compile."""
     mesh = mesh or make_host_mesh(data=1, model=1)
     opt = adamw(warmup_cosine(lr, max(steps // 20, 1), steps),
                 weight_decay=0.1)
@@ -48,14 +55,17 @@ def train(cfg, *, steps: int, batch_size: int, seq_len: int,
     else:
         print("wire audit: uncompressed f32 all-reduce (psum)")
 
-    losses = []
-    t0 = time.time()
+    losses, step_seconds = [], []
+    t0 = time.perf_counter()
     for step in range(steps):
+        t_step = time.perf_counter()
         batch = batch_for_shape(cfg, batch_size, seq_len, step, seed)
         params, opt_state, ef, metrics = tstep(params, opt_state, ef, batch)
+        jax.block_until_ready((params, opt_state, ef, metrics))
+        step_seconds.append(time.perf_counter() - t_step)
         losses.append(float(metrics["loss"]))
         if step % log_every == 0 or step == steps - 1:
-            dt = time.time() - t0
+            dt = time.perf_counter() - t0
             print(f"step {step:5d}  loss {losses[-1]:.4f}  "
                   f"gnorm {float(metrics['grad_norm']):.3f}  "
                   f"({dt:.1f}s)", flush=True)
@@ -63,7 +73,7 @@ def train(cfg, *, steps: int, batch_size: int, seq_len: int,
         path = save_checkpoint(ckpt_dir, steps, {"params": params,
                                                  "opt_state": opt_state})
         print(f"checkpoint → {path}")
-    return params, losses
+    return params, losses, step_seconds
 
 
 def main(argv=None):
@@ -86,6 +96,7 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get(args.arch))

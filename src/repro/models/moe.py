@@ -26,9 +26,8 @@ def _hint_expert_sharding(x: jax.Array) -> jax.Array:
     the output of the scatter pinned expert-sharded, the scatter partitions
     by index-masking per shard and the buffer never crosses the ICI.
     """
-    from repro.compat import get_mesh
-    mesh = get_mesh()
-    if (mesh is not None and "model" in mesh.axis_names
+    mesh = jax.sharding.get_abstract_mesh()
+    if ("model" in mesh.axis_names
             and mesh.shape["model"] > 1
             and x.shape[0] % mesh.shape["model"] == 0):
         from jax.sharding import PartitionSpec as P

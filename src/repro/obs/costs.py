@@ -39,21 +39,22 @@ import os
 from typing import Optional
 
 # ---------------------------------------------------------------------------
-# Per-backend peak table (device_kind prefix match first, backend fallback).
-# Dense-compute peaks in FLOP/s and HBM/DRAM stream bandwidth in bytes/s —
-# deliberately round numbers: the roofline fraction is an attribution aid
-# ("this span reaches 3% of peak"), not a measurement. Override with
-# REPRO_PEAK_FLOPS / REPRO_PEAK_BYTES (floats) for calibrated hardware.
+# Peak table, keyed by the prefix of `device.device_kind`: dense bf16
+# FLOP/s and HBM bytes/s per chip, from Google Cloud's TPU documentation.
+# "TPU v5 lite" is what a v5e chip reports; its row is the "TPU v5e" page:
+# 197 TFLOP/s bf16, 819 GB/s. A TPU whose kind is not here gets no peak
+# (attribution unavailable, with the reason) — never another chip's
+# figures. Non-TPU backends keep a nominal row, and REPRO_PEAK_FLOPS /
+# REPRO_PEAK_BYTES (floats) override everything for calibrated hardware.
 # ---------------------------------------------------------------------------
 DEVICE_PEAKS = (
+    ("TPU v5 lite", 197e12, 8.19e11),
     ("TPU v5p", 459e12, 2.77e12),
-    ("TPU v5e", 197e12, 8.2e11),
     ("TPU v4", 275e12, 1.2e12),
     ("TPU v3", 123e12, 9.0e11),
     ("TPU v2", 46e12, 7.0e11),
 )
 BACKEND_PEAKS = {
-    "tpu": (275e12, 1.2e12),
     "gpu": (1.0e14, 2.0e12),
     "cpu": (1.0e11, 5.0e10),   # one AVX-ish core complex + DDR stream
 }
@@ -64,8 +65,10 @@ def peaks(backend: Optional[str] = None,
     """{"flops_per_s", "bytes_per_s", "backend", "device_kind", "source"}.
 
     Resolution order: env override → device-kind prefix in DEVICE_PEAKS →
-    backend default → cpu default. Never raises (jax probing is guarded):
-    a missing accelerator yields the cpu row, with the source recorded.
+    backend default → cpu default. A TPU kind missing from DEVICE_PEAKS
+    resolves to None peaks with `source="unavailable"` and a `reason`.
+    Never raises (jax probing is guarded): a missing accelerator yields
+    the cpu row, with the source recorded.
     """
     if backend is None or device_kind is None:
         try:
@@ -88,6 +91,11 @@ def peaks(backend: Optional[str] = None,
                 return {"flops_per_s": fl, "bytes_per_s": by,
                         "backend": backend, "device_kind": device_kind,
                         "source": "device_table"}
+    if backend == "tpu":
+        return {"flops_per_s": None, "bytes_per_s": None, "backend": backend,
+                "device_kind": device_kind, "source": "unavailable",
+                "reason": f"TPU device_kind {device_kind!r} has no row in "
+                          "DEVICE_PEAKS"}
     fl, by = BACKEND_PEAKS.get(backend or "cpu", BACKEND_PEAKS["cpu"])
     return {"flops_per_s": fl, "bytes_per_s": by, "backend": backend,
             "device_kind": device_kind, "source": "backend_default"}
@@ -352,5 +360,7 @@ def attach_attrib(summary: dict, snap: dict) -> dict:
             attrib["roofline_frac"] = attrib["t_model_s"] / measured
             attrib["bound"] = ("flops" if (t_flops or 0.0) >= (t_bytes or 0.0)
                                else "bytes")
+        if pk.get("reason"):
+            attrib["unavailable"] = pk["reason"]
         sp["attrib"] = attrib
     return summary

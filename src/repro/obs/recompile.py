@@ -1,6 +1,6 @@
 """Recompile tracker: compilation-cache sizes of named jitted programs.
 
-jax 0.4.x jitted callables expose `_cache_size()` — the number of distinct
+jax jitted callables expose `_cache_size()` — the number of distinct
 (shape/dtype/static-arg) specializations compiled so far. Every jit factory
 in the hot layers registers its program here under a stable name
 ("fed.round.cohort", "dist.step", "serve.decode_step", …); `counts()`

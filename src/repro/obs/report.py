@@ -135,8 +135,8 @@ def render(summary: dict, title: str = "obs summary") -> str:
     if programs:
         pk = costs.get("peaks", {})
         lines.append(f"  costs (peaks: {pk.get('source', '?')} "
-                     f"{pk.get('flops_per_s', 0):.3g} FLOP/s, "
-                     f"{pk.get('bytes_per_s', 0):.3g} B/s)")
+                     f"{pk.get('flops_per_s') or 0:.3g} FLOP/s, "
+                     f"{pk.get('bytes_per_s') or 0:.3g} B/s)")
         lines.append(f"  {'program':<28} {'calls':>7} {'specs':>6} "
                      f"{'GFLOP':>9} {'GB acc':>9} {'wire MB':>9}")
         for name in sorted(programs):

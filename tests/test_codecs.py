@@ -127,8 +127,18 @@ def test_ratq_roundtrip_and_ledger(budget):
     assert ("mask" in wire["y"]) == (budget < 1.0)
     out = codec.decode(wire, meta)["y"]
     assert out.shape == (n,) and out.dtype == jnp.float32
-    err = float(jnp.linalg.norm(out - tree["y"])
-                / jnp.linalg.norm(tree["y"]))
+    # below 1 bit/dim the keep-mask is drawn from the encode key, so one
+    # draw's error is a sample of the codec's error: bound its RMS over
+    # eight draws. (Since jax_threefry_partitionable became the default,
+    # the same keys give other bits: this input and its masks changed, and
+    # a single draw sits anywhere in 0.81–1.08.)
+    sq_errs = []
+    for k in range(4, 12):
+        dec = codec.decode(codec.encode(jax.random.key(k), tree, 0),
+                           meta)["y"]
+        sq_errs.append(float(jnp.sum(jnp.square(dec - tree["y"]))
+                             / jnp.sum(jnp.square(tree["y"]))))
+    err = float(np.sqrt(np.mean(sq_errs)))
     assert err < (1.05 if budget < 4 else 0.3)
     # fixed-length wire: realized ledger equals the analytic audit exactly
     assert abs(codec.wire_bytes(wire, meta)

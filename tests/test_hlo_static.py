@@ -80,9 +80,9 @@ def test_collective_census_with_multiplier():
             return jax.lax.psum(c, "data"), None
         return jax.lax.scan(body, x, None, length=3)[0]
 
-    from repro.compat import shard_map
-    sm = shard_map(local, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                   axis_names={"data"}, check=False)
+    sm = jax.shard_map(local, mesh=mesh, in_specs=P("data"),
+                       out_specs=P("data"), axis_names={"data"},
+                       check_vma=False)
     txt = jax.jit(sm).lower(jnp.ones((4, 8))).compile().as_text()
     costs = hlo_static.analyze(txt)
     # 1-device meshes lower psum to no-op; just assert the parse runs

@@ -161,6 +161,32 @@ def test_peaks_device_table_prefix_match():
     assert pk["flops_per_s"] == 275e12
 
 
+def test_peaks_v5e_reports_as_v5_lite():
+    """A v5e chip reports device_kind "TPU v5 lite": it gets the published
+    v5e peaks (197 TFLOP/s bf16, 819 GB/s), not another chip's."""
+    pk = costs.peaks(backend="tpu", device_kind="TPU v5 lite")
+    assert pk["source"] == "device_table"
+    assert (pk["flops_per_s"], pk["bytes_per_s"]) == (197e12, 8.19e11)
+
+
+def test_peaks_unknown_tpu_kind_is_refused(monkeypatch):
+    monkeypatch.delenv("REPRO_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("REPRO_PEAK_BYTES", raising=False)
+    pk = costs.peaks(backend="tpu", device_kind="TPU v99")
+    assert pk["source"] == "unavailable"
+    assert pk["flops_per_s"] is None and pk["bytes_per_s"] is None
+    assert "TPU v99" in pk["reason"]
+    summary = {"spans": {"work": {"count": 1, "total_s": 2.0}}}
+    snap = {"peaks": pk,
+            "programs": {"prog": {"span": "work", "calls": 1,
+                                  "wire_bytes": 0.0, "flops_total": 100.0,
+                                  "bytes_total": 5.0, "cost_coverage": 1.0,
+                                  "specializations": []}}}
+    at = costs.attach_attrib(summary, snap)["spans"]["work"]["attrib"]
+    assert at["roofline_frac"] is None and at["t_model_s"] is None
+    assert at["unavailable"] == pk["reason"]
+
+
 def test_attach_attrib_roofline_math():
     summary = {"spans": {"work": {"count": 1, "total_s": 2.0, "mean_s": 2.0,
                                   "max_s": 2.0}}}

@@ -90,3 +90,42 @@ def test_multiworker_equivalence_subprocess():
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.count("EXACT") == 3
+
+
+def test_zero1_bitwise_with_error_feedback_over_two_steps():
+    """With error feedback, the second step adds the EF state to fresh
+    gradients: ZeRO-1 must still match allgather_packed bit for bit (the
+    embedding's scatter-add gradient is where a fused g + e would round
+    differently in the two layouts)."""
+    import jax
+    from repro import configs
+    from repro.data import batch_for_shape
+    from repro.dist import step as step_lib
+    from repro.launch.mesh import make_host_mesh
+    from repro.optimizer import sgd
+
+    if jax.device_count() < 4:
+        pytest.skip(f"needs 4 devices, have {jax.device_count()}")
+    mesh = make_host_mesh(data=4, model=1)
+    cfg = configs.get_reduced("xlstm-350m")
+    opt = sgd(1.0)
+    key = jax.random.key(0)
+    batches = [batch_for_shape(cfg, 8, 32, s, 0) for s in range(2)]
+    gc_a = GradCompConfig(bits=4, strategy="allgather_packed")
+    gc_z = GradCompConfig(bits=4, strategy="alltoall_zero1")
+    assert gc_a.uses_ef and gc_z.uses_ef
+    astep = step_lib.make_train_step(cfg, opt, gc_a, mesh)
+    zstep = step_lib.make_zero_train_step(cfg, opt, gc_z, mesh)
+    sa = step_lib.init_train_state(cfg, opt, gc_a, mesh, key)
+    sz = step_lib.init_zero_state(cfg, opt, gc_z, mesh, key)
+    for b in batches:
+        *sa, ma = astep(*sa, b)
+        *sz, mz = zstep(*sz, b)
+        assert float(ma["loss"]) == float(mz["loss"])
+    treedef, infos = zero_lib.params_meta(jax.eval_shape(lambda: sa[0]),
+                                          gc_z, 4)
+    owned = treedef.flatten_up_to(sz[0])
+    for o, (size, shape, _, _), want in zip(owned, infos,
+                                            jax.tree.leaves(sa[0])):
+        got = np.asarray(o).reshape(-1)[:size].reshape(shape)
+        np.testing.assert_array_equal(got, np.asarray(want))
