@@ -25,15 +25,16 @@ Consensus strategies (GradCompConfig.strategy):
 Error feedback is per-worker: e ← (g + e) − D(E(g + e)), decoded from the
 worker's OWN payload, so EF never needs extra communication.
 
-Observability: the returned step callables carry host-side
-instrumentation — with a `repro.obs` session active, each call runs under
-a "dist.step" span and emits per-step counters for the ANALYTIC per-worker
-payload bytes (from `gradcomp.wire_bytes_tree`, computed once at factory
-time — never from inside the compiled program). Disabled, the wrapper is
-one global load per call; the underlying jit program, its `lower` method
-and its compile cache are reachable via the wrapper (`_jitted`), and the
-program registers with `obs.recompile` so compile counts are attributable.
-Numerics are untouched either way (bit-exactness regression-tested).
+Observability: the step's phases run under the named scopes of
+`repro.dist.scopes` (forward, consensus with encode / exchange / decode /
+mean, optimizer), which reach every device op's metadata and leave the
+compiled instructions as they are. With a `repro.obs` session active,
+each call of the returned step runs under a host span ("dist.step",
+"dist.step.zero1"); disabled, the wrapper is one global load per call.
+The underlying jit program, its `lower` method and its compile cache are
+reachable via the wrapper (`_jitted`), and the program registers with
+`obs.recompile` so compile counts are attributable. Numerics are
+untouched either way (bit-exactness regression-tested).
 """
 from __future__ import annotations
 
@@ -45,6 +46,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.codecs import stages as codec_stages
 from repro.dist import gradcomp as G
+from repro.dist import scopes
 from repro.dist import zero as zero_lib
 from repro.dist.sharding import (data_axes_for, data_axis_names, num_workers,
                                  param_specs)
@@ -100,7 +102,7 @@ def _analytic_payload_bytes(cfg, gc: G.GradCompConfig, mesh):
 
 
 def _with_obs(fn, name: str, gc: G.GradCompConfig, payload_bytes):
-    """Host-side instrumentation around a jit'd train step. The wrapper is
+    """Host-side span around a jit'd train step. The wrapper is
     call-transparent (same signature, same outputs); `lower` and the
     compile cache stay reachable for the dry-run launcher and the tests."""
     recompile_lib.register(name, fn, wire_bytes_per_call=payload_bytes)
@@ -108,20 +110,23 @@ def _with_obs(fn, name: str, gc: G.GradCompConfig, payload_bytes):
     def stepper(params, opt_state, ef, batch):
         if not obs_lib.enabled():
             return fn(params, opt_state, ef, batch)
-        obs_lib.observe_program_call(name, fn,
-                                     (params, opt_state, ef, batch),
-                                     wire_bytes=payload_bytes)
         with obs_lib.span(name, strategy=gc.strategy):
-            out = fn(params, opt_state, ef, batch)
-        obs_lib.counter("dist.steps", 1, strategy=gc.strategy)
-        if payload_bytes is not None:
-            obs_lib.counter("dist.payload_bytes", payload_bytes,
-                            strategy=gc.strategy)
-        return out
+            return fn(params, opt_state, ef, batch)
 
     stepper.lower = fn.lower
     stepper._jitted = fn
     return stepper
+
+
+def _loss_and_grad(loss_of, params, batch):
+    """`jax.value_and_grad(loss_of)` under the forward scope. JAX names
+    the ops of the forward pass `forward/jvp(...)/...` and those of the
+    backward pass, rematerialized forward work included,
+    `forward/transpose(jvp(...))/...`. Inside the transform the scope
+    would rename the functions JAX inlines into the step; outside it, it
+    does not."""
+    with jax.named_scope(scopes.FORWARD):
+        return jax.value_and_grad(loss_of)(params, batch)
 
 
 # ---------------------------------------------------------------------------
@@ -138,45 +143,55 @@ def _pin(g):
 
 
 def _consensus(grads, ef, gc: G.GradCompConfig, axes, round_idx):
-    """Returns (consensus grads, new EF state).
+    """Returns (consensus grads, new EF state); the caller runs it under
+    the consensus scope.
 
     The per-leaf encode/decode routes through the NDSC stage codec from
     `repro.codecs.stages` — the same fused-kernel gradcomp implementation
     the fed engine and the registry use, so wire payloads here stay
     bit-identical with every other consumer of the codec stack."""
     if gc.strategy == "psum":
-        return jax.tree.map(lambda g: jax.lax.pmean(g, axes), grads), ef
+        with jax.named_scope(scopes.EXCHANGE):
+            return jax.tree.map(lambda g: jax.lax.pmean(g, axes), grads), ef
 
     leaf_codec = codec_stages.ndsc_leaf(gc)
     leaves, treedef = jax.tree.flatten(grads)
     e_leaves = treedef.flatten_up_to(ef) if gc.uses_ef else [None] * len(leaves)
     outs, new_e = [], []
     for i, (g, e) in enumerate(zip(leaves, e_leaves)):
-        u = _pin(g).astype(jnp.float32)
-        if e is not None:
-            u = u + e
-        resid = None
-        if gc.strategy == "allgather_packed" and gc.uses_ef:
-            # fused encode + EF: the kernel decodes its own payload in-tile
-            # and emits u − D(E(u)) alongside — no second decode pass
-            payload, resid = leaf_codec.encode_ef(u, i, round_idx)
-        else:
-            payload = leaf_codec.encode(u, i, round_idx)
+        with jax.named_scope(scopes.ENCODE):
+            u = _pin(g).astype(jnp.float32)
+            if e is not None:
+                u = u + e
+            resid = None
+            if gc.strategy == "allgather_packed" and gc.uses_ef:
+                # fused encode + EF: the kernel decodes its own payload
+                # in-tile and emits u − D(E(u)) alongside — no second
+                # decode pass
+                payload, resid = leaf_codec.encode_ef(u, i, round_idx)
+            else:
+                payload = leaf_codec.encode(u, i, round_idx)
         if gc.strategy == "psum_decoded":
             # the consensus itself needs the decoded leaf here, so EF
             # reuses it (u − (u − d) ≠ d in floats, so the fused residual
             # can't substitute)
-            d_own = leaf_codec.decode(payload, i, u.size, u.shape,
-                                      jnp.float32)
-            cons = jax.lax.pmean(d_own, axes)
+            with jax.named_scope(scopes.DECODE):
+                d_own = leaf_codec.decode(payload, i, u.size, u.shape,
+                                          jnp.float32)
+            with jax.named_scope(scopes.EXCHANGE):
+                cons = jax.lax.pmean(d_own, axes)
             if gc.uses_ef:
-                resid = u - d_own
+                with jax.named_scope(scopes.DECODE):
+                    resid = u - d_own
         else:  # allgather_packed
-            gathered = jax.tree.map(
-                lambda t: jax.lax.all_gather(t, axes, axis=0), payload)
-            stacked = leaf_codec.decode(gathered, i, u.size, u.shape,
-                                        jnp.float32, extra_lead=1)
-            cons = G.worker_mean(stacked)
+            with jax.named_scope(scopes.EXCHANGE):
+                gathered = jax.tree.map(
+                    lambda t: jax.lax.all_gather(t, axes, axis=0), payload)
+            with jax.named_scope(scopes.DECODE):
+                stacked = leaf_codec.decode(gathered, i, u.size, u.shape,
+                                            jnp.float32, extra_lead=1)
+            with jax.named_scope(scopes.MEAN):
+                cons = G.worker_mean(stacked)
         outs.append(cons.astype(g.dtype))
         if gc.uses_ef:
             new_e.append(resid)
@@ -202,19 +217,21 @@ def make_train_step(cfg, opt, gc: G.GradCompConfig, mesh, clip_norm=None,
     loss_of = loss_fn or (lambda p, b: model_lib.loss_fn(cfg, p, b))
 
     def local_step(params, opt_state, ef, batch):
-        loss, grads = jax.value_and_grad(loss_of)(params, batch)
+        loss, grads = _loss_and_grad(loss_of, params, batch)
         loss = jax.lax.pmean(loss, axes)
         # EF leaves carry a leading per-worker axis (m, …); local view (1, …)
         ef_local = jax.tree.map(lambda e: e[0], ef)
-        grads, ef_local = _consensus(grads, ef_local, gc, axes,
-                                     _round_idx(opt_state))
+        with jax.named_scope(scopes.CONSENSUS):
+            grads, ef_local = _consensus(grads, ef_local, gc, axes,
+                                         _round_idx(opt_state))
         ef = jax.tree.map(lambda e: e[None], ef_local)
-        if clip_norm is not None:
-            grads, grad_norm = clip_by_global_norm(grads, clip_norm)
-        else:
-            grad_norm = global_norm(grads)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            if clip_norm is not None:
+                grads, grad_norm = clip_by_global_norm(grads, clip_norm)
+            else:
+                grad_norm = global_norm(grads)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
         return params, opt_state, ef, {"loss": loss, "grad_norm": grad_norm}
 
     batch_spec = P(first)
@@ -319,7 +336,7 @@ def make_zero_train_step(cfg, opt, gc: G.GradCompConfig, mesh,
             full.append(zero_lib.from_owned(g.astype(jnp.float32),
                                             size, shape, dtype))
         params = jax.tree.unflatten(treedef, full)
-        loss, grads = jax.value_and_grad(loss_of)(params, batch)
+        loss, grads = _loss_and_grad(loss_of, params, batch)
         loss = jax.lax.pmean(loss, axes)
         round_idx = _round_idx(opt_state)
 
@@ -330,32 +347,40 @@ def make_zero_train_step(cfg, opt, gc: G.GradCompConfig, mesh,
         sq_sum = jnp.zeros((), jnp.float32)
         for i, (g, e, (size, shape, dtype, (padded, rows))) in enumerate(
                 zip(g_leaves, e_leaves, infos)):
-            u = zero_lib.to_owned(_pin(g), gc.chunk, m)
+            with jax.named_scope(scopes.CONSENSUS):
+                with jax.named_scope(scopes.ENCODE):
+                    u = zero_lib.to_owned(_pin(g), gc.chunk, m)
+                    if e is not None:
+                        u = u + e[0]
+                mean_own, resid = zero_lib.compressed_reduce_scatter(
+                    u, i, gc, axes, m, round_idx,
+                    logical_chunks=-(-size // gc.chunk))
+                # zero the padding coords so optimizer state / EF stay clean
+                # and the norms match the replicated path exactly
+                widx = _worker_index(axes, mesh) if m > 1 else 0
+                row0 = widx * rows
+                pos = ((row0 + jnp.arange(rows))[:, None] * gc.chunk
+                       + jnp.arange(gc.chunk)[None, :])
+                mean_own = mean_own * (pos < size).astype(jnp.float32)
+                owned_grads.append(mean_own)
+            with jax.named_scope(scopes.OPTIMIZER):
+                sq_sum = sq_sum + jnp.sum(jnp.square(mean_own))
             if e is not None:
-                u = u + e[0]
-            mean_own, resid = zero_lib.compressed_reduce_scatter(
-                u, i, gc, axes, m, round_idx,
-                logical_chunks=-(-size // gc.chunk))
-            # zero the padding coords so optimizer state / EF stay clean and
-            # the norms match the replicated path exactly
-            widx = _worker_index(axes, mesh) if m > 1 else 0
-            row0 = widx * rows
-            pos = ((row0 + jnp.arange(rows))[:, None] * gc.chunk
-                   + jnp.arange(gc.chunk)[None, :])
-            mean_own = mean_own * (pos < size).astype(jnp.float32)
-            owned_grads.append(mean_own)
-            sq_sum = sq_sum + jnp.sum(jnp.square(mean_own))
-            if e is not None:
-                new_e.append((resid
-                              * zero_lib.valid_mask(size, padded, gc.chunk)
-                              )[None])
-        grad_norm = jnp.sqrt(jax.lax.psum(sq_sum, axes))
-        owned_grads = jax.tree.unflatten(treedef, owned_grads)
-        if clip_norm is not None:
-            scale = jnp.minimum(1.0, clip_norm / jnp.maximum(grad_norm, 1e-12))
-            owned_grads = jax.tree.map(lambda x: x * scale, owned_grads)
-        updates, opt_state = opt.update(owned_grads, opt_state, owned_params)
-        owned_params = apply_updates(owned_params, updates)
+                with jax.named_scope(scopes.CONSENSUS), \
+                        jax.named_scope(scopes.ENCODE):
+                    new_e.append((resid
+                                  * zero_lib.valid_mask(size, padded,
+                                                        gc.chunk))[None])
+        with jax.named_scope(scopes.OPTIMIZER):
+            grad_norm = jnp.sqrt(jax.lax.psum(sq_sum, axes))
+            owned_grads = jax.tree.unflatten(treedef, owned_grads)
+            if clip_norm is not None:
+                scale = jnp.minimum(1.0,
+                                    clip_norm / jnp.maximum(grad_norm, 1e-12))
+                owned_grads = jax.tree.map(lambda x: x * scale, owned_grads)
+            updates, opt_state = opt.update(owned_grads, opt_state,
+                                            owned_params)
+            owned_params = apply_updates(owned_params, updates)
         ef = jax.tree.unflatten(treedef, new_e) if gc.uses_ef else ef
         return owned_params, opt_state, ef, {"loss": loss,
                                              "grad_norm": grad_norm}

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist import gradcomp as G
+from repro.dist import scopes
 
 
 def leaf_layout(shape, chunk: int, num_workers: int) -> tuple:
@@ -85,12 +86,13 @@ def compressed_reduce_scatter(u: jax.Array, leaf_idx: int,
     """
     rows = u.shape[0] // num_workers
     residual = None
-    if gc.uses_ef:
-        payload, residual = G.encode_leaf_ef(u, leaf_idx, gc, round_idx,
-                                             logical_chunks=logical_chunks)
-    else:
-        payload = G.encode_leaf(u, leaf_idx, gc, round_idx,
-                                logical_chunks=logical_chunks)
+    with jax.named_scope(scopes.ENCODE):
+        if gc.uses_ef:
+            payload, residual = G.encode_leaf_ef(
+                u, leaf_idx, gc, round_idx, logical_chunks=logical_chunks)
+        else:
+            payload = G.encode_leaf(u, leaf_idx, gc, round_idx,
+                                    logical_chunks=logical_chunks)
 
     def route(t):
         tm = t.reshape((num_workers, rows) + t.shape[1:])
@@ -99,7 +101,11 @@ def compressed_reduce_scatter(u: jax.Array, leaf_idx: int,
         return jax.lax.all_to_all(tm, axes, split_axis=0, concat_axis=0,
                                   tiled=False)
 
-    gathered = jax.tree.map(route, payload)      # (m, rows, …) per wire leaf
-    stacked = G.decode_leaf(gathered, leaf_idx, rows * gc.chunk,
-                            (rows, gc.chunk), jnp.float32, gc, extra_lead=1)
-    return G.worker_mean(stacked), residual
+    with jax.named_scope(scopes.EXCHANGE):
+        gathered = jax.tree.map(route, payload)  # (m, rows, …) per wire leaf
+    with jax.named_scope(scopes.DECODE):
+        stacked = G.decode_leaf(gathered, leaf_idx, rows * gc.chunk,
+                                (rows, gc.chunk), jnp.float32, gc,
+                                extra_lead=1)
+    with jax.named_scope(scopes.MEAN):
+        return G.worker_mean(stacked), residual

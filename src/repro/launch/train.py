@@ -14,11 +14,12 @@ drives this module with a fixed recipe.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
 
-from repro import configs
+from repro import configs, obs
 from repro.checkpoint import save_checkpoint
 from repro.data import batch_for_shape
 from repro.dist import step as step_lib
@@ -28,6 +29,14 @@ from repro.launch.mesh import make_host_mesh
 from repro.optimizer import adamw, warmup_cosine
 
 
+def _step_annotation(step: int):
+    """Marks one loop step in a profiler trace while a `repro.obs` session
+    is active, so each idle gap of the device falls under a step."""
+    if not obs.enabled():
+        return contextlib.nullcontext()
+    return jax.profiler.StepTraceAnnotation("train", step_num=step)
+
+
 def train(cfg, *, steps: int, batch_size: int, seq_len: int,
           gc: GradCompConfig, lr: float = 3e-4, log_every: int = 10,
           ckpt_dir: str | None = None, mesh=None, seed: int = 0):
@@ -35,7 +44,10 @@ def train(cfg, *, steps: int, batch_size: int, seq_len: int,
 
     Returns (params, per-step losses, per-step wall seconds); each step's
     time ends when its outputs are ready, and the first one includes the
-    trace and compile."""
+    trace and compile. With a `repro.obs` session active, each step is a
+    profiler step, with spans `train.batch`, `train.step` (the dispatch;
+    `dist.step` nests inside it) and `train.wait` (until the outputs are
+    ready), and the checkpoint is a `train.checkpoint` span."""
     mesh = mesh or make_host_mesh(data=1, model=1)
     opt = adamw(warmup_cosine(lr, max(steps // 20, 1), steps),
                 weight_decay=0.1)
@@ -59,9 +71,14 @@ def train(cfg, *, steps: int, batch_size: int, seq_len: int,
     t0 = time.perf_counter()
     for step in range(steps):
         t_step = time.perf_counter()
-        batch = batch_for_shape(cfg, batch_size, seq_len, step, seed)
-        params, opt_state, ef, metrics = tstep(params, opt_state, ef, batch)
-        jax.block_until_ready((params, opt_state, ef, metrics))
+        with _step_annotation(step):
+            with obs.span("train.batch"):
+                batch = batch_for_shape(cfg, batch_size, seq_len, step, seed)
+            with obs.span("train.step"):
+                params, opt_state, ef, metrics = tstep(params, opt_state, ef,
+                                                       batch)
+            with obs.span("train.wait"):
+                jax.block_until_ready((params, opt_state, ef, metrics))
         step_seconds.append(time.perf_counter() - t_step)
         losses.append(float(metrics["loss"]))
         if step % log_every == 0 or step == steps - 1:
@@ -70,8 +87,9 @@ def train(cfg, *, steps: int, batch_size: int, seq_len: int,
                   f"gnorm {float(metrics['grad_norm']):.3f}  "
                   f"({dt:.1f}s)", flush=True)
     if ckpt_dir:
-        path = save_checkpoint(ckpt_dir, steps, {"params": params,
-                                                 "opt_state": opt_state})
+        with obs.span("train.checkpoint"):
+            path = save_checkpoint(ckpt_dir, steps, {"params": params,
+                                                     "opt_state": opt_state})
         print(f"checkpoint → {path}")
     return params, losses, step_seconds
 

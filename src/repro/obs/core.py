@@ -8,7 +8,10 @@ their arguments — so instrumentation can live permanently on the host-side
 hot paths. None of it ever runs inside jit-compiled code: spans time the
 host's view of a dispatch (`time.perf_counter`), which includes device
 work only insofar as the call blocks; pair with the `jax.profiler`
-passthrough (`enable(jax_trace_dir=...)`) for device timelines.
+passthrough (`enable(jax_trace_dir=...)`) for device timelines. An active
+session's span also enters a `jax.profiler.TraceAnnotation` of its name,
+so every program span lands in a profiler trace's host plane, on the
+device trace's clock (jax is imported on the first such span only).
 
 The hard contract the fed/dist regression tests pin: enabling obs changes
 no numerics (params/EF/ledger/history bit-exact with disabled) and causes
@@ -53,11 +56,25 @@ class _NoopSpan:
 
 
 NOOP_SPAN = _NoopSpan()
+_annotation = None     # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _profiler_annotation(name: str):
+    """A `jax.profiler.TraceAnnotation` of `name` (NOOP_SPAN without jax):
+    the profiler records it on its host plane when a trace is running."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation as _annotation
+        except ImportError:
+            _annotation = lambda name: NOOP_SPAN  # noqa: E731
+    return _annotation(name)
 
 
 class Span:
-    """A wall-clock span; emits one event on exit. Use via `obs.span(...)`."""
-    __slots__ = ("_obs", "name", "attrs", "_t0")
+    """A wall-clock span; emits one event on exit and, while open, holds a
+    profiler annotation of the same name. Use via `obs.span(...)`."""
+    __slots__ = ("_obs", "name", "attrs", "_t0", "_ann")
 
     def __init__(self, obs: "Obs", name: str, attrs: dict):
         self._obs = obs
@@ -67,11 +84,14 @@ class Span:
     def __enter__(self):
         tls = self._obs._tls
         tls.depth = getattr(tls, "depth", 0) + 1
+        self._ann = _profiler_annotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         o = self._obs
         depth = o._tls.depth
         o._tls.depth = depth - 1

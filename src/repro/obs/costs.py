@@ -4,9 +4,9 @@ The paper's claims are *cost* claims — O(n²) multiplications for the exact
 embedding, O(n log n) additions for the Hadamard relaxation, R bits per
 dimension on the wire — so a measured span is only half a result; this
 module supplies the analytic half. Every named jitted program (the ones
-`repro.obs.recompile` tracks: fed.round.*, fed.aggregate.*,
-dist.step{,.zero1}, serve.{prefill,decode_step}, the kernel dispatch
-wrappers) can be asked, per compiled specialization it was actually called
+`repro.obs.recompile` tracks and whose call site captures it: fed.round.*,
+fed.aggregate.*, serve.{prefill,decode_step}, the kernel dispatch wrappers)
+can be asked, per compiled specialization it was actually called
 with, what the compiler says it does: FLOPs and bytes accessed from XLA's
 HLO cost analysis, argument/output byte footprints, plus the analytic
 wire-bytes the codec audit charges per call. A per-backend peak table then

@@ -210,16 +210,17 @@ def test_dist_step_bit_exact_and_no_extra_recompiles(mesh):
     assert float(m_off["loss"]) == float(m_on["loss"])
     assert compiles_on == compiles_off
     s = o.summary()
-    assert s["counters"]["dist.steps"]["total"] == 2.0
-    assert s["counters"]["dist.payload_bytes"]["total"] > 0
-    assert "dist.step" in s["spans"]
+    # the step emits one span per call and nothing else: no per-step
+    # counters, no cost-model capture of the train step
+    span = s["spans"]["dist.step"]
+    assert span["count"] == 2
+    steps = [e for e in o.memory_events()
+             if e["type"] == "span" and e["name"] == "dist.step"]
+    assert [e["attrs"] for e in steps] == [{"strategy": gc.strategy}] * 2
+    assert not [n for n in s["counters"] if n.startswith("dist.")]
 
     base = recompile.counts()
     snap = o.costs()
     assert recompile.counts() == base
-    prog = snap["programs"]["dist.step"]
-    assert prog["calls"] == 2 and prog["wire_bytes"] > 0
-    for spec in prog["specializations"]:
-        assert spec["available"] or spec["reason"]
-    attrib = s["spans"]["dist.step"]["attrib"]
-    assert attrib["calls_observed"] == 2
+    assert "dist.step" not in snap["programs"]
+    assert "attrib" not in span
