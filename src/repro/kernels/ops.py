@@ -11,7 +11,9 @@ VMEM budget) raises instead of silently substituting the reference — a
 silent fallback would make "forced Pallas" tests vacuous.
 
 Observability: every dispatch decision increments the
-`kernels.dispatch` counter (attrs: op, path "pallas"|"ref", N, forced)
+`kernels.dispatch` counter (attrs: op, path "pallas"|"ref", N, forced,
+and on the Pallas path block_rows, the rows per grid step that
+`fwht.block_rows_for` gives the call's row count)
 and a refused forced dispatch increments `kernels.forced_error` BEFORE
 raising — so a CI run under REPRO_FORCE_PALLAS=1 can assert "zero
 reference-fallback events" from the event stream instead of relying on
@@ -30,6 +32,7 @@ Compile-time parameters (bits, n, …) are closed over with
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -52,9 +55,16 @@ def _forced() -> bool:
     return os.environ.get("REPRO_FORCE_PALLAS") == "1"
 
 
-def _count_dispatch(op: str, path: str, n) -> None:
+def _rows(x: jax.Array) -> int:
+    return math.prod(x.shape[:-1])
+
+
+def _count_dispatch(op: str, path: str, n, rows: int | None = None) -> None:
+    """`rows`: the call's chunk rows, given on the Pallas path only."""
+    attrs = {} if rows is None else {
+        "block_rows": _fwht_kernel.block_rows_for(int(n), rows)}
     obs.counter("kernels.dispatch", 1, op=op, path=path, n=int(n),
-                forced=_forced())
+                forced=_forced(), **attrs)
 
 
 def _count_forced_error(op: str, n) -> None:
@@ -70,7 +80,7 @@ def fwht(x: jax.Array) -> jax.Array:
     """Normalized Walsh–Hadamard transform along the last axis (power-of-2 len)."""
     if _use_pallas():
         if x.shape[-1] <= _fwht_kernel.MAX_VMEM_N:
-            _count_dispatch("fwht", "pallas", x.shape[-1])
+            _count_dispatch("fwht", "pallas", x.shape[-1], _rows(x))
             _observe("fwht", "pallas", _fwht_kernel.fwht_pallas, (x,))
             return _fwht_kernel.fwht_pallas(x)
         if _forced():
@@ -100,7 +110,7 @@ def unrotate(x: jax.Array, signs: jax.Array) -> jax.Array:
 def quantize_pack(x: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
     """Fused uniform-quantize + bit-pack to int32 words (bits ∈ {1,2,4,8})."""
     if _use_pallas():
-        _count_dispatch("quantize_pack", "pallas", x.shape[-1])
+        _count_dispatch("quantize_pack", "pallas", x.shape[-1], _rows(x))
         _observe("quantize_pack", "pallas",
                  functools.partial(_quantpack_kernel.quantize_pack_pallas,
                                    bits=bits),
@@ -116,7 +126,7 @@ def quantize_pack(x: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
 def unpack_dequant(words: jax.Array, scale: jax.Array, bits: int, n: int) -> jax.Array:
     """Fused unpack + dequantize (inverse of quantize_pack)."""
     if _use_pallas():
-        _count_dispatch("unpack_dequant", "pallas", n)
+        _count_dispatch("unpack_dequant", "pallas", n, _rows(words))
         _observe("unpack_dequant", "pallas",
                  functools.partial(_quantpack_kernel.unpack_dequant_pallas,
                                    bits=bits, n=n),
@@ -141,7 +151,8 @@ def encode(chunks: jax.Array, signs: jax.Array, bits: int, *,
     under REPRO_FORCE_PALLAS=1, like `fwht`)."""
     if _use_pallas():
         if chunks.shape[-1] <= _quantencode_kernel.MAX_VMEM_N:
-            _count_dispatch("encode", "pallas", chunks.shape[-1])
+            _count_dispatch("encode", "pallas", chunks.shape[-1],
+                            _rows(chunks))
             _observe("encode", "pallas",
                      functools.partial(_quantencode_kernel.encode_pallas,
                                        bits=bits),
@@ -176,7 +187,8 @@ def encode_ef(chunks: jax.Array, signs: jax.Array, bits: int, *,
     rdt = jnp.float32 if residual_dtype is None else residual_dtype
     if _use_pallas():
         if chunks.shape[-1] <= _quantencode_kernel.MAX_VMEM_N:
-            _count_dispatch("encode_ef", "pallas", chunks.shape[-1])
+            _count_dispatch("encode_ef", "pallas", chunks.shape[-1],
+                            _rows(chunks))
             _observe("encode_ef", "pallas",
                      functools.partial(_quantencode_kernel.encode_ef_pallas,
                                        bits=bits, rescale=rescale,

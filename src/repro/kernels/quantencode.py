@@ -8,8 +8,11 @@ between every stage: the f32 embedding is written out after the FWHT, read
 back for the scale reduction, written again after the dither… This kernel
 does the whole chain inside one (block_rows, N) VMEM tile, so the f32
 embedding NEVER touches HBM — HBM traffic drops to "read y once, write
-N·bits/32 words + one f32 scale per row", the codec's information-theoretic
-minimum (gated in `benchmarks/codec_roofline.py`).
+N·bits/32 words + one f32 scale per row" (gated in
+`benchmarks/codec_roofline.py`). Speed is another matter: on a v5e the
+step's codec kernels ran at 2.04% of the time that minimum traffic needs
+at peak bandwidth with 8-row tiles, and at 24% with the 512-row tiles of
+`fwht.block_rows_for` (`codec_roofline`, Yi-6B stage, 4 bits).
 
 A fused error-feedback variant (`encode_ef_pallas`) additionally
 dequantizes its own codes in-tile, inverse-rotates, and emits the
@@ -36,11 +39,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fwht import MAX_VMEM_N, fwht_tile
+from repro.kernels.fwht import MAX_VMEM_N, block_rows_for, fwht_tile
 from repro.kernels.quantpack import (dequantize_codes, pack_tile,
                                      quantize_tile)
-
-DEFAULT_BLOCK_ROWS = 8
 
 
 def _encode_kernel(*refs, bits: int, n: int, dithered: bool, masked: bool,
@@ -94,7 +95,7 @@ def _encode_kernel(*refs, bits: int, n: int, dithered: bool, masked: bool,
 @functools.partial(
     jax.jit, static_argnames=("bits", "block_rows", "interpret", "ef",
                               "rescale", "residual_dtype"))
-def _encode_call(x, signs, dither, mask, *, bits: int, block_rows: int,
+def _encode_call(x, signs, dither, mask, *, bits: int, block_rows: int | None,
                  interpret, ef: bool, rescale, residual_dtype):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -111,19 +112,15 @@ def _encode_call(x, signs, dither, mask, *, bits: int, block_rows: int,
     lead = x.shape[:-1]
     flat = x.astype(jnp.float32).reshape((-1, n))
     rows = flat.shape[0]
-    padded = -(-rows // block_rows) * block_rows
+    block_rows = min(block_rows or block_rows_for(n, rows), rows)
     signs2d = signs.astype(jnp.float32).reshape((1, n))
 
-    def pad(t):
-        return (t if t.shape[0] == padded
-                else jnp.pad(t, ((0, padded - t.shape[0]), (0, 0))))
-
     row_spec = pl.BlockSpec((block_rows, n), lambda i: (i, 0))
-    inputs = [pad(flat)]
+    inputs = [flat]
     if dither is not None:
-        inputs.append(pad(dither.astype(jnp.float32).reshape((-1, n))))
+        inputs.append(dither.astype(jnp.float32).reshape((-1, n)))
     if mask is not None:
-        inputs.append(pad(mask.astype(jnp.float32).reshape((-1, 1))))
+        inputs.append(mask.astype(jnp.float32).reshape((-1, 1)))
     # signs go FIRST after x in the kernel's operand order
     inputs.insert(1, signs2d)
     in_specs = [row_spec, pl.BlockSpec((1, n), lambda i: (0, 0))]
@@ -132,12 +129,12 @@ def _encode_call(x, signs, dither, mask, *, bits: int, block_rows: int,
     if mask is not None:
         in_specs.append(pl.BlockSpec((block_rows, 1), lambda i: (i, 0)))
 
-    out_shape = [jax.ShapeDtypeStruct((padded, n // k), jnp.int32),
-                 jax.ShapeDtypeStruct((padded, 1), jnp.float32)]
+    out_shape = [jax.ShapeDtypeStruct((rows, n // k), jnp.int32),
+                 jax.ShapeDtypeStruct((rows, 1), jnp.float32)]
     out_specs = [pl.BlockSpec((block_rows, n // k), lambda i: (i, 0)),
                  pl.BlockSpec((block_rows, 1), lambda i: (i, 0))]
     if ef:
-        out_shape.append(jax.ShapeDtypeStruct((padded, n), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((rows, n), jnp.float32))
         out_specs.append(row_spec)
 
     kernel = functools.partial(
@@ -146,30 +143,31 @@ def _encode_call(x, signs, dither, mask, *, bits: int, block_rows: int,
         residual_dtype=residual_dtype)
     outs = pl.pallas_call(
         kernel,
-        grid=(padded // block_rows,),
+        grid=(pl.cdiv(rows, block_rows),),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
     )(*inputs)
-    words = outs[0][:rows].reshape(lead + (n // k,))
-    scale = outs[1][:rows].reshape(lead + (1,))
+    words = outs[0].reshape(lead + (n // k,))
+    scale = outs[1].reshape(lead + (1,))
     if ef:
-        return words, scale, outs[2][:rows].reshape(lead + (n,))
+        return words, scale, outs[2].reshape(lead + (n,))
     return words, scale
 
 
 def encode_pallas(chunks: jax.Array, signs: jax.Array, bits: int, *,
                   dither: jax.Array | None = None,
                   mask: jax.Array | None = None,
-                  block_rows: int = DEFAULT_BLOCK_ROWS,
+                  block_rows: int | None = None,
                   interpret: bool | None = None) -> tuple:
     """Fused codec encode — semantics of `ref.encode` in one VMEM pass.
 
     chunks: (..., N) float rows (N a power of 2, ≤ MAX_VMEM_N, divisible by
     the 32/bits packing factor); signs: (N,) ±1; dither/mask as in
-    `ref.encode` (pre-drawn OUTSIDE the kernel). `interpret=None` infers
-    from the backend (compiled on TPU, interpreter elsewhere).
+    `ref.encode` (pre-drawn OUTSIDE the kernel). `block_rows=None` takes
+    `fwht.block_rows_for`; `interpret=None` infers from the backend
+    (compiled on TPU, interpreter elsewhere).
     Returns (words int32 (..., N·bits/32), scale f32 (..., 1)).
     """
     return _encode_call(chunks, signs, dither, mask, bits=bits,
@@ -182,7 +180,7 @@ def encode_ef_pallas(chunks: jax.Array, signs: jax.Array, bits: int, *,
                      mask: jax.Array | None = None,
                      rescale: float | None = None,
                      residual_dtype=jnp.float32,
-                     block_rows: int = DEFAULT_BLOCK_ROWS,
+                     block_rows: int | None = None,
                      interpret: bool | None = None) -> tuple:
     """Fused encode + error-feedback residual — semantics of `ref.encode_ef`.
 

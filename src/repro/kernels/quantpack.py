@@ -14,6 +14,13 @@ tiles the W words k times along the lanes and shifts each slot back down.
 No value ever splits the 128-lane dimension, and all integer work is int32
 with logical right shifts (bits=1 uses bit 31). `ref.quantize_pack` /
 `ref.unpack_dequant` define the same layout with the same float ops.
+
+Tiling follows the FWHT's rule on the f32 side's length N:
+`fwht.block_rows_for` rows per grid step (512 at N = 256, 680 at
+N <= 128), each block computed whole in VMEM. Rows are independent, so
+each is packed or unpacked exactly as in an 8-row tile. A leaf whose rows
+are not a multiple of the block runs a partial last block; a leaf
+smaller than one block is one block of all its rows.
 """
 from __future__ import annotations
 
@@ -24,8 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-DEFAULT_BLOCK_ROWS = 8
+from repro.kernels.fwht import block_rows_for
 
 
 def quantize_tile(x: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
@@ -74,22 +80,14 @@ def _unpackdequant_kernel(w_ref, scale_ref, o_ref, *, bits: int):
     o_ref[...] = values * scale_ref[...]
 
 
-def _tile(call, flat_inputs, block_rows):
-    rows = flat_inputs[0].shape[0]
-    padded = -(-rows // block_rows) * block_rows
-    if padded != rows:
-        flat_inputs = [jnp.pad(a, ((0, padded - rows), (0, 0))) for a in flat_inputs]
-    out = call(padded, flat_inputs)
-    return out[:rows]
-
-
 @functools.partial(jax.jit, static_argnames=("bits", "block_rows", "interpret"))
 def quantize_pack_pallas(x: jax.Array, scale: jax.Array, bits: int,
-                         block_rows: int = DEFAULT_BLOCK_ROWS,
+                         block_rows: int | None = None,
                          interpret: bool | None = None) -> jax.Array:
     """x: (..., N) float, scale: (..., 1) → packed int32 (..., N*bits/32).
 
-    interpret=None infers from the backend (compiled on TPU)."""
+    block_rows=None takes `fwht.block_rows_for`; interpret=None infers
+    from the backend (compiled on TPU)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if bits not in (1, 2, 4, 8):
@@ -101,30 +99,28 @@ def quantize_pack_pallas(x: jax.Array, scale: jax.Array, bits: int,
     lead = x.shape[:-1]
     flat_x = x.reshape((-1, n))
     flat_s = jnp.broadcast_to(scale, lead + (1,)).reshape((-1, 1))
-
-    def call(padded_rows, inputs):
-        grid = (padded_rows // block_rows,)
-        return pl.pallas_call(
-            functools.partial(_quantpack_kernel, bits=bits),
-            grid=grid,
-            in_specs=[pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-                      pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((block_rows, n // k), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((padded_rows, n // k), jnp.int32),
-            interpret=interpret,
-        )(*inputs)
-
-    out = _tile(call, [flat_x, flat_s], block_rows)
+    rows = flat_x.shape[0]
+    block_rows = min(block_rows or block_rows_for(n, rows), rows)
+    out = pl.pallas_call(
+        functools.partial(_quantpack_kernel, bits=bits),
+        grid=(pl.cdiv(rows, block_rows),),
+        in_specs=[pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
+                  pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((block_rows, n // k), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, n // k), jnp.int32),
+        interpret=interpret,
+    )(flat_x, flat_s)
     return out.reshape(lead + (n // k,))
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "n", "block_rows", "interpret"))
 def unpack_dequant_pallas(words: jax.Array, scale: jax.Array, bits: int, n: int,
-                          block_rows: int = DEFAULT_BLOCK_ROWS,
+                          block_rows: int | None = None,
                           interpret: bool | None = None) -> jax.Array:
     """words: (..., N*bits/32) int32, scale: (..., 1) → float (..., n).
 
-    interpret=None infers from the backend (compiled on TPU)."""
+    block_rows=None takes `fwht.block_rows_for`; interpret=None infers
+    from the backend (compiled on TPU)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if bits not in (1, 2, 4, 8):
@@ -135,18 +131,15 @@ def unpack_dequant_pallas(words: jax.Array, scale: jax.Array, bits: int, n: int,
     lead = words.shape[:-1]
     flat_w = words.reshape((-1, words.shape[-1]))
     flat_s = jnp.broadcast_to(scale, lead + (1,)).reshape((-1, 1)).astype(jnp.float32)
-
-    def call(padded_rows, inputs):
-        grid = (padded_rows // block_rows,)
-        return pl.pallas_call(
-            functools.partial(_unpackdequant_kernel, bits=bits),
-            grid=grid,
-            in_specs=[pl.BlockSpec((block_rows, n // k), lambda i: (i, 0)),
-                      pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((padded_rows, n), jnp.float32),
-            interpret=interpret,
-        )(*inputs)
-
-    out = _tile(call, [flat_w, flat_s], block_rows)
+    rows = flat_w.shape[0]
+    block_rows = min(block_rows or block_rows_for(n, rows), rows)
+    out = pl.pallas_call(
+        functools.partial(_unpackdequant_kernel, bits=bits),
+        grid=(pl.cdiv(rows, block_rows),),
+        in_specs=[pl.BlockSpec((block_rows, n // k), lambda i: (i, 0)),
+                  pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, n), jnp.float32),
+        interpret=interpret,
+    )(flat_w, flat_s)
     return out.reshape(lead + (n,))

@@ -16,10 +16,15 @@ from repro.kernels import ref
 # FWHT
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("n", [2, 8, 64, 128, 1024])
-@pytest.mark.parametrize("lead", [(), (1,), (5,), (3, 4)])
-def test_fwht_pallas_matches_ref(n, lead):
+@pytest.mark.parametrize("lead,block_rows", [
+    pytest.param((), None, id="lead0"), pytest.param((1,), None, id="lead1"),
+    pytest.param((5,), None, id="lead2"),
+    pytest.param((3, 4), None, id="lead3"),
+    # an explicit 16-row block over 13 rows: one block of all of them
+    pytest.param((13,), 16, id="lead13-block16")])
+def test_fwht_pallas_matches_ref(n, lead, block_rows):
     x = jax.random.normal(jax.random.key(0), lead + (n,))
-    got = fwht_kernel.fwht_pallas(x, interpret=True)
+    got = fwht_kernel.fwht_pallas(x, block_rows=block_rows, interpret=True)
     np.testing.assert_allclose(got, ref.fwht(x), rtol=1e-5, atol=1e-5)
 
 
@@ -52,6 +57,30 @@ def test_fwht_matches_hadamard_matrix():
     np.testing.assert_allclose(ref.fwht(jnp.asarray(x)), x @ h, atol=1e-5)
 
 
+@pytest.mark.parametrize("n,rows,want", [
+    (256, 176128, 512),     # Yi-6B's MLP leaf: 2 KiB of VMEM a row
+    (256, 201728, 512),     # the xlstm-350m embedding leaf
+    (8192, 64, 24),         # the single-tile limit N
+    (128, 100003, 680),     # n <= 128: each row padded to 128 lanes
+    (64, 100003, 680),
+    (32, 100003, 680),      # the fed quickstart's chunk
+    (256, 13, 13),          # a leaf under one block: one block of it all
+    (256, 1, 1),
+    (8192, 3, 3),
+])
+def test_block_rows_for(n, rows, want):
+    """About TILE_BYTES of VMEM per block, each row's f32 values, words
+    and scale counted at their 128-lane padded widths: a multiple of 8,
+    at least 8, or the leaf's rows when it has fewer than one block."""
+    got = fwht_kernel.block_rows_for(n, rows)
+    assert got == want
+    if got < rows:
+        assert got % 8 == 0 and got >= 8
+    lanes = lambda w: -(-w // 128) * 128
+    row_bytes = 4 * (lanes(n) + lanes(n // 4) + 128)
+    assert got * row_bytes <= max(fwht_kernel.TILE_BYTES, 8 * row_bytes)
+
+
 def test_fwht_rejects_non_pow2():
     with pytest.raises(ValueError):
         fwht_kernel.fwht_pallas(jnp.zeros((2, 48)), interpret=True)
@@ -61,14 +90,21 @@ def test_fwht_rejects_non_pow2():
 # quantize-pack / unpack-dequant
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("bits", [1, 2, 4, 8])
-@pytest.mark.parametrize("rows,n", [(1, 32), (7, 128), (16, 1024)])
-def test_quantpack_pallas_matches_ref(bits, rows, n):
+@pytest.mark.parametrize("rows,n,block_rows", [
+    pytest.param(1, 32, None, id="1-32"), pytest.param(7, 128, None, id="7-128"),
+    pytest.param(16, 1024, None, id="16-1024"),
+    # an explicit 16-row block over a single row: one 1-row block
+    pytest.param(1, 128, 16, id="1-128-block16")])
+def test_quantpack_pallas_matches_ref(bits, rows, n, block_rows):
     x = jax.random.normal(jax.random.key(3), (rows, n))
     scale = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    got = qp_kernel.quantize_pack_pallas(x, scale, bits, interpret=True)
+    got = qp_kernel.quantize_pack_pallas(x, scale, bits,
+                                         block_rows=block_rows,
+                                         interpret=True)
     want = ref.quantize_pack(x, scale, bits)
     np.testing.assert_array_equal(got, want)
     back = qp_kernel.unpack_dequant_pallas(got, scale, bits, n,
+                                           block_rows=block_rows,
                                            interpret=True)
     np.testing.assert_allclose(back, ref.unpack_dequant(want, scale, bits, n),
                                atol=1e-6)
@@ -142,9 +178,13 @@ def _draws(rows, n, bits, seed=11):
 
 
 @pytest.mark.parametrize("bits", [1, 2, 4, 8])
-@pytest.mark.parametrize("rows,n", [(1, 32), (8, 128), (13, 256)])
+@pytest.mark.parametrize("rows,n,block_rows", [
+    pytest.param(1, 32, None, id="1-32"), pytest.param(8, 128, None, id="8-128"),
+    pytest.param(13, 256, None, id="13-256"),
+    # two 16-row blocks and a partial third of 5 rows
+    pytest.param(37, 128, 16, id="37-128-block16")])
 @pytest.mark.parametrize("mode", ["det", "dither", "mask", "dither_mask"])
-def test_fused_encode_payload_bitexact(bits, rows, n, mode):
+def test_fused_encode_payload_bitexact(bits, rows, n, block_rows, mode):
     """The PAYLOAD contract: fused-kernel (words, scale) == composed ref,
     bit for bit — deterministically and with shared pre-drawn draws."""
     x = jax.random.normal(jax.random.key(bits * 100 + rows), (rows, n))
@@ -153,7 +193,7 @@ def test_fused_encode_payload_bitexact(bits, rows, n, mode):
     dth = dither if "dither" in mode else None
     msk = mask if "mask" in mode else None
     kw, ks = qe_kernel.encode_pallas(x, signs, bits, dither=dth, mask=msk,
-                                     interpret=True)
+                                     block_rows=block_rows, interpret=True)
     rw, rs = ref.encode(x, signs, bits, dither=dth, mask=msk)
     np.testing.assert_array_equal(kw, rw)
     np.testing.assert_array_equal(np.asarray(ks).view(np.int32),
@@ -161,9 +201,12 @@ def test_fused_encode_payload_bitexact(bits, rows, n, mode):
 
 
 @pytest.mark.parametrize("bits", [1, 4])
-@pytest.mark.parametrize("rows,n", [(5, 128), (13, 64)])
+@pytest.mark.parametrize("rows,n,block_rows", [
+    pytest.param(5, 128, None, id="5-128"), pytest.param(13, 64, None, id="13-64"),
+    # two 16-row blocks and a partial third of 8 rows
+    pytest.param(40, 64, 16, id="40-64-block16")])
 @pytest.mark.parametrize("mode", ["det", "dither_mask", "rescale"])
-def test_fused_encode_ef_residual(bits, rows, n, mode):
+def test_fused_encode_ef_residual(bits, rows, n, block_rows, mode):
     """The EF contract: payload stays bitwise; the in-tile residual matches
     the composed eager reference u − D(E(u)) to a few f32 ulp of the
     embedding scale (fma contraction in the in-tile decode is allowed)."""
@@ -175,7 +218,7 @@ def test_fused_encode_ef_residual(bits, rows, n, mode):
     rescale = 0.6 if mode == "rescale" else None
     kw, ks, kr = qe_kernel.encode_ef_pallas(
         x, signs, bits, dither=dth, mask=msk, rescale=rescale,
-        interpret=True)
+        block_rows=block_rows, interpret=True)
     rw, rs, rr = ref.encode_ef(x, signs, bits, dither=dth, mask=msk,
                                rescale=rescale)
     np.testing.assert_array_equal(kw, rw)

@@ -223,6 +223,20 @@ def test_kernel_dispatch_counter(monkeypatch):
     assert attrs["path"] in ("pallas", "ref") and attrs["forced"] is False
 
 
+def test_kernel_dispatch_counter_block_rows(monkeypatch):
+    """On the Pallas path the counter names the tile the call took: the
+    rows per grid step `block_rows_for` gives its row count."""
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    o = obs.enable()
+    ops.fwht(jnp.ones((3, 13, 256)))
+    obs.disable()
+    (event,) = [e for e in o.memory_events()
+                if e["name"] == "kernels.dispatch"]
+    assert event["attrs"]["path"] == "pallas"
+    assert event["attrs"]["block_rows"] == fwht_kernel.block_rows_for(256, 39)
+    assert event["attrs"]["block_rows"] == 39
+
+
 def test_forced_dispatch_error_counts_and_raises(monkeypatch):
     """Satellite: the forced-pallas refusal must BOTH report through the obs
     counter and keep raising."""
